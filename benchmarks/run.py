@@ -39,6 +39,8 @@ def main() -> None:
                     help="also write every metric as JSON records "
                          "(e.g. BENCH_engine.json) for CI tracking")
     args = ap.parse_args()
+    from repro.utils import compile_cache
+    compile_cache.enable()
     only = set(args.only.split(",")) if args.only else None
     header()
     failures = []

@@ -438,7 +438,18 @@ print("SERVE_SHARDED_JSON=" + json.dumps(
 
 
 def _sharded_pass(quick: bool) -> None:
+    import jax
+
     from repro.core import distributed, projections
+
+    if jax.default_backend() == "tpu":
+        # the pass times a forced-8-device CPU child; on a chip host its
+        # numbers would sit beside the chip's in one report
+        print("# serve/sharded: refused on a TPU host -- this pass times "
+              "8 virtual CPU devices in a child process; the mesh path "
+              "on the chip runs in `python chip_smoke.py --chips 4`",
+              file=sys.stderr)
+        return
 
     cfg = {"slots": SHARD_SLOTS, "n1": SHARD_N1, "n2": SHARD_N2,
            "d": D, "iters": SHARD_ITERS, "chunk": SHARD_CHUNK,

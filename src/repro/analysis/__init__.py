@@ -7,8 +7,9 @@ Two layers, one gate (``python -m repro.analysis.run``):
   evaluation of each BlockSpec index map over the full grid (including
   adversarial scalar-prefetched index vectors spanning ``[0, d)``):
   block bounds (BLOCK-001), output coverage (COVER-001), write-write
-  races across grid points (RACE-001) and the per-grid-point VMEM
-  footprint against the 16 MiB TPU budget (VMEM-001).
+  races across grid points (RACE-001), the per-grid-point VMEM
+  footprint against the 16 MiB TPU budget (VMEM-001) and Mosaic's
+  (8, 128) block tiling rule (TILE-001).
 
 * :mod:`repro.analysis.hlo_lint` -- Layer 2.  A rule-based lint over
   the AOT-lowered (compiled, post-optimization) HLO of the serving /

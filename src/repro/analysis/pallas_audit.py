@@ -19,6 +19,12 @@ RACE-001   an output block revisited by multiple grid points is a
            on TPU's revisit-flush output semantics
 VMEM-001   double-buffered blocks + scratch + kernel temporaries fit
            the 16 MiB per-core VMEM budget at 4 bytes/element
+TILE-001   every block satisfies Mosaic's tiling rule: the last two
+           block dims are divisible by (8, 128) or equal the operand's
+           dims, and a rank-1 block is a multiple of 128 or the whole
+           length (squeezed dims count as 1; a whole-array SMEM block is
+           exempt, as in the TPU lowering).  Interpret mode accepts any
+           block; the chip's compiler refuses these.
 
 Zero findings over :func:`audit_cases` is a CI gate
 (``python -m repro.analysis.run``; see scripts/ci.sh).
@@ -36,14 +42,17 @@ ELEM_BYTES = 4                     # f32; upper bound for bf16 operands
 
 #: serving bucket rungs: preprocess.bucket_length pads every fit() to
 #: 128 * 2^k, so these are exactly the n_pad values the slot engine
-#: can launch kernels at (16384 covers the largest CI/bench bucket).
-SERVING_RUNGS = tuple(128 * 2 ** k for k in range(8))
+#: can launch kernels at, up to the service's default max_points (2^20).
+SERVING_RUNGS = tuple(128 * 2 ** k for k in range(14))
 
-DEFAULT_TILE = 1024                # engine launch default (kernels cap it)
+#: the slot counts a packed kernel is launched at: a solo solve (S=1)
+#: and the service's default lane group (S=8)
+SLOT_COUNTS = (1, 8)
 
 
 class Finding(NamedTuple):
-    rule: str          # BLOCK-001 / COVER-001 / RACE-001 / VMEM-001
+    rule: str          # BLOCK-001 / COVER-001 / RACE-001 / VMEM-001 /
+                       # TILE-001
     kernel: str
     case: str
     detail: str
@@ -70,6 +79,17 @@ def registry() -> dict[str, Callable[..., dict]]:
 
 
 # ------------------------------------------------------------- evaluation
+
+def _block_dims(spec) -> tuple[int, ...]:
+    """Block shape with squeezed (``None``) dims counted as 1."""
+    return tuple(1 if b is None or not isinstance(b, int) else b
+                 for b in spec.block_shape)
+
+
+def _in_smem(spec) -> bool:
+    from jax.experimental.pallas import tpu as pltpu
+    return spec.memory_space == pltpu.SMEM
+
 
 def _grid_points(grid: tuple[int, ...]) -> list[np.ndarray]:
     """Flattened coordinate arrays, one (G,) array per grid axis, in
@@ -119,7 +139,7 @@ def _check_blocks(prog, coords, idx, variant, case, findings) -> None:
             ("in", prog["in_specs"], prog["in_shapes"]),
             ("out", prog["out_specs"], prog["out_shapes"])):
         for pos, (spec, full) in enumerate(zip(specs, fulls)):
-            block = tuple(spec.block_shape)
+            block = _block_dims(spec)
             binds = _eval_index_map(spec, coords, idx)
             if binds.shape[1] != len(block) or len(block) != len(full):
                 findings.append(Finding(
@@ -144,7 +164,7 @@ def _check_outputs(prog, coords, idx, variant, case, findings) -> None:
     grid = prog["grid"]
     for pos, (spec, full) in enumerate(zip(prog["out_specs"],
                                            prog["out_shapes"])):
-        block = tuple(spec.block_shape)
+        block = _block_dims(spec)
         if len(block) != len(full):
             continue                       # already a BLOCK-001
         binds = _eval_index_map(spec, coords, idx)
@@ -202,8 +222,9 @@ def _check_outputs(prog, coords, idx, variant, case, findings) -> None:
 
 def _check_vmem(prog, case, findings) -> None:
     block_bytes = sum(
-        int(math.prod(spec.block_shape)) * ELEM_BYTES
-        for spec in (*prog["in_specs"], *prog["out_specs"]))
+        int(math.prod(_block_dims(spec))) * ELEM_BYTES
+        for spec in (*prog["in_specs"], *prog["out_specs"])
+        if not _in_smem(spec))
     total = (2 * block_bytes                     # double-buffered DMA
              + prog["scratch_bytes"] + prog["extra_vmem_bytes"])
     if total > VMEM_BUDGET:
@@ -215,8 +236,34 @@ def _check_vmem(prog, case, findings) -> None:
             f"{VMEM_BUDGET} B budget"))
 
 
+def tiling_ok(block: tuple[int, ...], full: tuple[int, ...]) -> bool:
+    """Mosaic's block-shape rule for one (f32) block of ``full``."""
+    if len(block) == 1:
+        return block[0] == full[0] or block[0] % 128 == 0
+    (b1, b0), (a1, a0) = block[-2:], full[-2:]
+    return (b0 == a0 or b0 % 128 == 0) and (b1 == a1 or b1 % 8 == 0)
+
+
+def _check_tiling(prog, case, findings) -> None:
+    for role, specs, fulls in (
+            ("in", prog["in_specs"], prog["in_shapes"]),
+            ("out", prog["out_specs"], prog["out_shapes"])):
+        for pos, (spec, full) in enumerate(zip(specs, fulls)):
+            block, full = _block_dims(spec), tuple(full)
+            if _in_smem(spec) and block == full:
+                continue
+            if len(block) != len(full) or tiling_ok(block, full):
+                continue          # a rank mismatch is already BLOCK-001
+            findings.append(Finding(
+                "TILE-001", prog["name"], case,
+                f"{role}[{pos}]: block {tuple(spec.block_shape)} of "
+                f"{full}: the last two dims must be divisible by "
+                "(8, 128) or equal the operand's (a rank-1 block: a "
+                "multiple of 128 or the whole length)"))
+
+
 def audit_program(prog: dict, *, case: str = "") -> list[Finding]:
-    """All four checks over one concrete kernel program."""
+    """All five checks over one concrete kernel program."""
     findings: list[Finding] = []
     coords = _grid_points(prog["grid"])
     for variant, idx in _idx_variants(prog):
@@ -227,6 +274,7 @@ def audit_program(prog: dict, *, case: str = "") -> list[Finding]:
         _check_blocks(prog, coords, idx, tag, case, findings)
         _check_outputs(prog, coords, idx, tag, case, findings)
     _check_vmem(prog, case, findings)
+    _check_tiling(prog, case, findings)
     return findings
 
 
@@ -243,33 +291,31 @@ def audit_cases(*, dryrun_mesh_sizes: tuple[int, ...] = (256, 512),
     per-client dry-run shard shapes of both production meshes, and the
     preprocessing FWHT tiles."""
     from repro.kernels.fwht import auto_tile_n
-    from repro.kernels.saddle_update import _packed_tile
+    from repro.kernels.saddle_update import UNPACKED_TILE, _unpacked_tile
     from repro.launch.specs import (SADDLE_DSVC_SHAPES,
                                     saddle_dsvc_client_shape)
 
     cases: list[AuditCase] = []
     for n_pad in SERVING_RUNGS:
-        tile = min(DEFAULT_TILE, n_pad)
+        tile = _unpacked_tile(n_pad, UNPACKED_TILE)
         for b in (1, 8, 128):
             kw = dict(n_pad=n_pad, b=b, tile=tile)
             lbl = f"rung n_pad={n_pad} b={b} tile={tile}"
             cases.append(AuditCase("momentum_dot", lbl, dict(kw)))
             cases.append(AuditCase("mwu_update", lbl, dict(kw)))
-        ptile = _packed_tile(n_pad, DEFAULT_TILE)
-        for d in (32, 256):
+        for d in (64, 256):
             for b in _packed_bs(d):
-                kw = dict(n_pad=n_pad, d=d, b=b, tile=ptile)
-                lbl = (f"rung n_pad={n_pad} d={d} b={b} tile={ptile}")
-                cases.append(AuditCase("momentum_dot_packed", lbl,
-                                       dict(kw)))
-                cases.append(AuditCase("mwu_update_packed", lbl,
-                                       dict(kw)))
+                for s in SLOT_COUNTS:
+                    kw = dict(n_pad=n_pad, d=d, b=b, num_slots=s)
+                    lbl = f"rung n_pad={n_pad} d={d} b={b} S={s}"
+                    cases.append(AuditCase("momentum_dot_packed", lbl,
+                                           dict(kw)))
+                    cases.append(AuditCase("mwu_update_packed", lbl,
+                                           dict(kw)))
     for k in dryrun_mesh_sizes:
         for shape in SADDLE_DSVC_SHAPES.values():
             cs = saddle_dsvc_client_shape(shape, k)
-            ptile = _packed_tile(cs["n_pad"], DEFAULT_TILE)
-            kw = dict(n_pad=cs["n_pad"], d=cs["d"], b=cs["b"],
-                      tile=ptile)
+            kw = dict(n_pad=cs["n_pad"], d=cs["d"], b=cs["b"])
             lbl = (f"dryrun {shape.name} k={k} n_pad={cs['n_pad']} "
                    f"d={cs['d']} b={cs['b']}")
             cases.append(AuditCase("momentum_dot_packed", lbl, dict(kw)))

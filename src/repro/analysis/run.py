@@ -66,7 +66,9 @@ def main(argv: list[str] | None = None) -> int:
                       **{"BLOCK-001": "every block in bounds",
                          "COVER-001": "every output block written",
                          "RACE-001": "revisits are declared accumulation",
-                         "VMEM-001": "blocks+scratch fit 16 MiB"}),
+                         "VMEM-001": "blocks+scratch fit 16 MiB",
+                         "TILE-001": "blocks meet Mosaic's (8, 128) "
+                                     "tiling rule"}),
         "kernel_cases": kernel_records,
         "hlo_targets": hlo_records,
         "findings": all_findings,

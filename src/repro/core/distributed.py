@@ -345,7 +345,6 @@ def sharded_run_fn(mesh: jax.sharding.Mesh, axis=CLIENT_AXIS,
     launch specs can AOT-lower the exact production chunk from
     ShapeDtypeStructs without allocating anything."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     def run(state, key, x_t, sign, num_steps):
         def client_fn(st, x_t_c, sign_c, key_r, ns_r):
@@ -357,9 +356,9 @@ def sharded_run_fn(mesh: jax.sharding.Mesh, axis=CLIENT_AXIS,
             return jax.tree.map(lambda a: a[None], st), obj[None]
 
         spec = P(axis)
-        fn = shard_map(client_fn, mesh=mesh,
-                       in_specs=(spec, spec, spec, P(), P()),
-                       out_specs=(spec, spec), check_rep=False)
+        fn = jax.shard_map(client_fn, mesh=mesh,
+                           in_specs=(spec, spec, spec, P(), P()),
+                           out_specs=(spec, spec), check_vma=False)
         return fn(state, x_t, sign, key, jnp.asarray(num_steps, jnp.int32))
 
     return run
@@ -370,6 +369,9 @@ def make_sharded_runner(mesh: jax.sharding.Mesh, axis=CLIENT_AXIS,
     """shard_map runner for a real device mesh: the production path used
     by the multi-pod dry-run (clients = the mesh 'data' axis), running
     the packed single-sweep chunk per shard."""
+    from repro.launch.mesh import auto_axes
+
+    mesh = auto_axes(mesh)
 
     @functools.partial(jax.jit,
                        static_argnames=("params", "chunk_steps"),

@@ -38,7 +38,9 @@ column-major mirror ``x_t`` (d, n_pad): a sampled block is b CONTIGUOUS
 rows (``jnp.take(x_t, idx, axis=0)``), not b strided columns of a
 row-major (n, d) matrix; the Pallas kernels go further and gather
 tile-by-tile inside the kernel from scalar-prefetched indices, never
-materializing a cols intermediate.
+materializing a cols intermediate (they read ``x_t`` through a
+(d, n_pad/128, 128) row-tile view that the chunk drivers build once
+per call, see ``kernels.saddle_update``).
 
 The nu-Saddle capped-simplex projection is SORT-FREE: a fixed-round
 bisection on the cap scale (the shared core
@@ -491,7 +493,9 @@ def _step_packed_core(state: PackedState, key: jax.Array, x_t: jax.Array,
     """The packed iteration parameterized by step SCALARS (see
     :class:`SlotParams`): shared verbatim by the classic per-problem
     step (python-float scalars) and the slot-batched driver (traced
-    per-slot scalars under ``vmap``)."""
+    per-slot scalars under ``vmap``).  On the pallas backend ``x_t``
+    may already be the kernels' row-tile view (:func:`_step_operand`);
+    the jnp backend reads the (d, n_pad) operand."""
     d_eff = d / block_size
     idx = sample_block(key, d, block_size)
     if backend == "pallas":
@@ -558,6 +562,16 @@ def objective_packed(state: PackedState, x_t: jax.Array, sign: jax.Array,
     return objective_from_duals(state.log_lam, x_t, sign, axis_name)
 
 
+def _step_operand(x_t: jax.Array, backend: str) -> jax.Array:
+    """The operand the packed step reads: on the pallas backend, the
+    kernels' row-tile view (``saddle_update.row_tiles``) -- one relayout
+    per chunk, outside the step loop, instead of one per kernel launch."""
+    if backend != "pallas":
+        return x_t
+    from repro.kernels.saddle_update import row_tiles
+    return row_tiles(x_t)
+
+
 def chunk_body_packed(state, key, x_t, sign, params, num_steps, *,
                       chunk_steps: int, axis_name: str | None = None,
                       backend: str = "jnp"):
@@ -566,9 +580,10 @@ def chunk_body_packed(state, key, x_t, sign, params, num_steps, *,
     trace_counts[("packed", axis_name, backend, chunk_steps)] += 1
 
     keys = jax.random.split(key, chunk_steps)
+    x_step = _step_operand(x_t, backend)
 
     def body(i, st):
-        return step_packed(st, keys[i], x_t, sign, params,
+        return step_packed(st, keys[i], x_step, sign, params,
                            axis_name=axis_name, backend=backend)
 
     state = jax.lax.fori_loop(0, num_steps, body, state)
@@ -773,6 +788,7 @@ def chunk_body_slots(state: SlotState, x_t: jax.Array, sign: jax.Array,
     splits = jax.vmap(jax.random.split)(state.key)   # (S, 2)
     chain, chunk_key = splits[:, 0], splits[:, 1]
     keys = jax.vmap(lambda k: jax.random.split(k, chunk_steps))(chunk_key)
+    x_step = _step_operand(x_t, backend)
 
     def step_slot(ps, key_i, x_t_i, sign_i, row):
         return _step_packed_core(ps, key_i, x_t_i, sign_i, row, d=d,
@@ -782,7 +798,7 @@ def chunk_body_slots(state: SlotState, x_t: jax.Array, sign: jax.Array,
     def body(i, st):
         ps = PackedState(w=st.w, log_lam=st.log_lam,
                          log_lam_prev=st.log_lam_prev, u=st.u, t=st.t)
-        new = jax.vmap(step_slot)(ps, keys[:, i], x_t, sign, sp)
+        new = jax.vmap(step_slot)(ps, keys[:, i], x_step, sign, sp)
         do = st.active & (st.t < st.max_t)           # (S,)
         sel = lambda n, o: jnp.where(
             do.reshape((-1,) + (1,) * (n.ndim - 1)), n, o)
@@ -867,10 +883,9 @@ def sharded_slot_run_fn(mesh: jax.sharding.Mesh, *, slot_axes=(),
     axis shards over ``slot_axes``, the point axis over ``point_axes``
     (disjoint; either may be empty).  Per-slot lifecycle rows (``t``,
     ``max_t``, ``key``, ``active``) and ``w`` are replicated across
-    ``point_axes``; ``check_rep=False`` because psum-produced outputs
+    ``point_axes``; ``check_vma=False`` because psum-produced outputs
     defeat shard_map's static replication check.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     slot_axes, point_axes = tuple(slot_axes), tuple(point_axes)
@@ -895,11 +910,11 @@ def sharded_slot_run_fn(mesh: jax.sharding.Mesh, *, slot_axes=(),
             block_size=block_size, project=project, check_gap=check_gap,
             backend=backend, axis_name=axis_name)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(state_spec, P(s, None, p), P(s, p), sp_spec, P()),
         out_specs=(state_spec, P(s), P(s)),
-        check_rep=False)
+        check_vma=False)
 
 
 @functools.lru_cache(maxsize=None)

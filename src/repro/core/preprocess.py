@@ -58,12 +58,19 @@ def fwht(x: jax.Array, *, normalize: bool = True) -> jax.Array:
 
 
 LANE = 128  # TPU lane width; packed point counts are padded to this
+SUBLANE = 8  # f32 rows per TPU memory tile
 
 
 def packed_length(n: int, lane: int = LANE) -> int:
     """Smallest multiple of ``lane`` >= n (>= lane, so the Pallas tile
-    grid always divides evenly)."""
-    return max(-(-n // lane), 1) * lane
+    grid always divides evenly) -- and, beyond ``SUBLANE`` lanes, of
+    ``SUBLANE * lane``: the kernels view the point axis as rows of
+    ``lane`` points and block them in whole (8, 128) tiles
+    (``kernels.saddle_update.tile_rows``)."""
+    rows = max(-(-n // lane), 1)
+    if rows > SUBLANE:
+        rows = -(-rows // SUBLANE) * SUBLANE
+    return rows * lane
 
 
 def bucket_length(n: int, lane: int = LANE) -> int:

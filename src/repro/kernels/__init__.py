@@ -4,16 +4,16 @@
 
 
 def default_interpret() -> bool:
-    """Pallas ``interpret=`` default: real kernels on TPU, interpreter
-    everywhere else.
+    """Pallas ``interpret=`` default: compiled kernels on TPU, the
+    interpreter everywhere else.
 
-    The BlockSpecs are TPU-shaped (lane-aligned tiles, full-d VMEM
-    blocks), so on a TPU build the kernels compile for real without any
-    flag threading; CPU/GPU hosts (this container) fall back to the
-    interpreter, which is what every parity test runs against.  The
-    static shape discipline the compiled path needs is proven
-    separately by :mod:`repro.analysis.pallas_audit` over the same
-    program builders the launches use.
+    The interpreter accepts blocks the TPU compiler refuses, so an
+    interpret-mode pass says nothing about the chip.  What the compiled
+    path needs is checked twice off the chip: the static auditor
+    (:mod:`repro.analysis.pallas_audit`, including Mosaic's tiling rule
+    TILE-001) over the program builders the launches use, and
+    ``tests/test_tpu_compile.py``, which compiles the kernels and the
+    slot chunk for a described v5e with ``interpret=False``.
     """
     import jax
 

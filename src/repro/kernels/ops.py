@@ -3,11 +3,12 @@
 ``interpret=None`` (the default everywhere) resolves through
 :func:`repro.kernels.default_interpret`: real compiled kernels when
 ``jax.default_backend() == "tpu"``, the Pallas interpreter otherwise
-(this container is CPU-only).  The resolution happens OUTSIDE the jitted
-kernel impls, so the static ``interpret`` cache key is always a concrete
-bool.  The TPU-shaped BlockSpec discipline the compiled path relies on
-is statically verified by ``repro.analysis.pallas_audit`` over the same
-program builders the launches use.
+(a CPU-only host).  The resolution happens OUTSIDE the jitted kernel
+impls, so the static ``interpret`` cache key is always a concrete bool.
+The TPU block discipline the compiled path relies on is checked by
+``repro.analysis.pallas_audit`` over the same program builders the
+launches use, and by compiling for a described v5e in
+``tests/test_tpu_compile.py``.
 
 ``launch_counts`` tallies pallas_call launches per wrapper at TRACE
 time (one wrapper call == one kernel launch in the compiled step).
@@ -61,8 +62,8 @@ def mwu_update(cols, log_lam, u, dw, *, sign, gamma, tau, d_eff,
 def momentum_dot_packed(x_t, idx, log_lam, log_prev, sign, theta, *,
                         interpret=None):
     """Single-sweep signed momentum dot over the packed operand; the
-    coordinate block is gathered from the raw column-major mirror
-    inside the kernel (scalar-prefetched indices)."""
+    coordinate block is gathered from the column-major mirror (or its
+    row-tile view) inside the kernel (scalar-prefetched indices)."""
     launch_counts["momentum_dot_packed"] += 1
     return _su.momentum_dot_packed(x_t, idx, log_lam, log_prev, sign,
                                    theta, interpret=interpret)

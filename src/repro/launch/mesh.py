@@ -17,11 +17,31 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
 
 
 def make_test_mesh(devices: int | None = None) -> jax.sharding.Mesh:
-    """Small mesh over whatever devices exist (unit tests)."""
+    """Small (data, model) mesh over the first ``devices`` devices
+    (all of them by default), with auto-sharded axes (see
+    :func:`auto_axes`)."""
     n = devices or len(jax.devices())
     model = 1
     for cand in (4, 2, 1):
         if n % cand == 0:
             model = cand
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return auto_axes(jax.make_mesh((n // model, model), ("data", "model"),
+                                   devices=jax.devices()[:n]))
+
+
+def auto_axes(mesh: jax.sharding.Mesh) -> jax.sharding.Mesh:
+    """The same devices and axis names with every axis ``Auto``.
+
+    The solver's mesh paths run their per-iteration work inside
+    ``shard_map`` and leave everything around it -- lane writes,
+    admission scatters, result indexing -- to the compiler's sharding
+    propagation.  ``jax.make_mesh`` makes ``Explicit`` axes, under which
+    each of those scatters and gathers on a sharded axis needs its own
+    ``out_sharding``; callers that accept a user's mesh normalize it
+    here instead."""
+    auto = jax.sharding.AxisType.Auto
+    if all(t == auto for t in mesh.axis_types):
+        return mesh
+    return jax.sharding.Mesh(mesh.devices, mesh.axis_names,
+                             axis_types=(auto,) * len(mesh.axis_names))

@@ -311,6 +311,14 @@ def _write_slot_data(x_t_b, sign_b, slot, x_t, sign):
     return x_t_b.at[slot].set(x_t), sign_b.at[slot].set(sign)
 
 
+def _zero_buffers(num_slots: int, n_pad: int, d_pad: int):
+    """A FREE slot table plus zero (S, d, n) operand and (S, n) sign
+    buffers for one bucket."""
+    return (engine.init_slot_state(num_slots, n_pad, d_pad),
+            jnp.zeros((num_slots, d_pad, n_pad), jnp.float32),
+            jnp.zeros((num_slots, n_pad), jnp.float32))
+
+
 class _Batch:
     """One bucket's DEVICE buffers: slot-batched engine state, the
     (S, d, n) packed operands and the per-slot SlotParams mirror.  The
@@ -333,9 +341,11 @@ class _Batch:
         spans the mesh and the chunk runs the Theorem-8 collective
         rounds (large-n fits; see ``engine.run_chunk_slots_sharded``).
 
-    The buffers are created under :class:`~jax.sharding.NamedSharding`
-    so the first chunk already lowers at the placement the whole group
-    lifetime keeps."""
+    The buffers are created directly under their
+    :class:`~jax.sharding.NamedSharding` -- each device allocates only
+    its own shard, so a group sized to the whole mesh's memory can be
+    admitted -- and the first chunk already lowers at the placement the
+    whole group lifetime keeps."""
 
     def __init__(self, bucket: tuple[int, int], num_slots: int,
                  project: bool, check_gap: bool,
@@ -347,9 +357,6 @@ class _Batch:
         self.check_gap = check_gap
         self.mesh = mesh
         self.point_sharded = point_sharded
-        self.state = engine.init_slot_state(num_slots, n_pad, d_pad)
-        self.x_t = jnp.zeros((num_slots, d_pad, n_pad), jnp.float32)
-        self.sign = jnp.zeros((num_slots, n_pad), jnp.float32)
         self.sp = jax.tree.map(
             lambda v: np.repeat(np.asarray(v, np.float32), num_slots),
             engine.SlotParams(theta=0.0, sigma=0.0, inv_sig1=1.0,
@@ -361,6 +368,8 @@ class _Batch:
             self.point_axes: tuple = ()
             self.shardings = None
             self.sp_sharding = None
+            self.state, self.x_t, self.sign = _zero_buffers(
+                num_slots, n_pad, d_pad)
         else:
             axes = tuple(mesh.axis_names)
             self.slot_axes, self.point_axes = (
@@ -381,9 +390,9 @@ class _Batch:
             self.sp_sharding = engine.SlotParams(
                 *(mk(PartitionSpec(s))
                   for _ in engine.SlotParams._fields))
-            self.state = jax.device_put(self.state, state_sh)
-            self.x_t = jax.device_put(self.x_t, self.shardings[1])
-            self.sign = jax.device_put(self.sign, self.shardings[2])
+            self.state, self.x_t, self.sign = jax.jit(
+                _zero_buffers, static_argnums=(0, 1, 2),
+                out_shardings=self.shardings)(num_slots, n_pad, d_pad)
 
     def ensure_placement(self) -> None:
         """Re-pin any buffer whose sharding drifted off the batch's
@@ -439,6 +448,9 @@ class SolverService:
         # A 1-device mesh reproduces the meshless service bit-for-bit:
         # shard_map over one device partitions nothing and the chunk
         # body is the identical computation.
+        if mesh is not None:
+            from repro.launch.mesh import auto_axes
+            mesh = auto_axes(mesh)
         self.mesh = mesh
         self._mesh_k = 1 if mesh is None else int(mesh.size)
         if mesh is not None and num_slots % self._mesh_k:

@@ -247,7 +247,6 @@ def lower_step(k: int, *, n1: int, n2: int, d: int, nu: float,
     """Compile ONE ``engine.step_packed`` iteration under shard_map on a
     k-client mesh and return the post-SPMD HLO text."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import engine, saddle
@@ -267,9 +266,9 @@ def lower_step(k: int, *, n1: int, n2: int, d: int, nu: float,
         return jax.tree.map(lambda a: a[None], st)
 
     spec = P(axis)
-    fn = shard_map(client, mesh=mesh,
-                   in_specs=(spec, spec, spec, P()), out_specs=spec,
-                   check_rep=False)
+    fn = jax.shard_map(client, mesh=mesh,
+                       in_specs=(spec, spec, spec, P()), out_specs=spec,
+                       check_vma=False)
     return jax.jit(fn).lower(state, x_t, sign, key).compile().as_text()
 
 

@@ -101,8 +101,8 @@ def test_seeded_racing_output_blockspec_race_001():
         "bad_race", grid=(4, 8),
         in_shapes=[(512,)],
         in_specs=[pl.BlockSpec((128,), lambda i, j: (i,))],
-        out_shapes=[(4,)],
-        out_specs=[pl.BlockSpec((1,), lambda i, j: (i,))])
+        out_shapes=[(512,)],
+        out_specs=[pl.BlockSpec((128,), lambda i, j: (i,))])
     assert _rules(pa.audit_program(prog, case="seed")) == {"RACE-001"}
 
 
@@ -111,11 +111,67 @@ def test_real_packed_accumulation_is_not_a_race():
     its declared accum_axes it must pass, and stripping the
     declaration must turn exactly that revisit into RACE-001."""
     from repro.kernels.saddle_update import mwu_update_packed_program
-    prog = mwu_update_packed_program(n_pad=512, d=32, b=8, tile=128)
+    prog = mwu_update_packed_program(n_pad=2048, d=32, b=8, tile=1024,
+                                     num_slots=2)
     assert pa.audit_program(prog, case="real") == []
     tampered = dict(prog, accum_axes={})
     assert _rules(pa.audit_program(tampered, case="tampered")) == \
         {"RACE-001"}
+
+
+# What the chip's compiler refused before the kernels moved to the
+# row-tile layout: a one-row gather block of the (d, n_pad) operand,
+# (1, 1)/(1, 4) per-tile partial outputs, and rank-1 (1,) outputs.
+# Interpret mode runs all of them; TILE-001 must refuse each.
+_TILE_SEEDS = {
+    "x_t_row_gather": ((1, 1024), (256, 4096)),
+    "squeezed_row_gather": ((None, 1024), (256, 4096)),
+    "scalar_partial": ((1, 1), (4, 128)),
+    "class_partials": ((1, 4), (4, 4)),
+    "rank1_partial": ((1,), (4,)),
+    "rank1_unaligned": ((200,), (1000,)),
+    "sublane_unaligned": ((12, 128), (48, 128)),
+}
+
+
+@pytest.mark.parametrize("seed", sorted(_TILE_SEEDS))
+def test_seeded_untiled_block_tile_001(seed):
+    block, full = _TILE_SEEDS[seed]
+    zeros = (0,) * len(full)
+    prog = _fake_prog(
+        seed, grid=(1,),
+        in_shapes=[full], in_specs=[pl.BlockSpec(block, lambda i: zeros)],
+        out_shapes=[(1024,)],
+        out_specs=[pl.BlockSpec((1024,), lambda i: (0,))])
+    assert "TILE-001" in _rules(pa.audit_program(prog, case="seed"))
+
+
+@pytest.mark.parametrize("block,full,ok", [
+    ((8, 128), (64, 1024), True),
+    ((1, 512), (1, 4096), True),          # second-minor equals the dim
+    ((None, 16, 128), (4, 16, 128), True),  # leading dims are free
+    ((4096,), (4096,), True),
+    ((256,), (4096,), True),
+    ((1, 128), (8, 128), False),
+    ((8, 64), (8, 1024), False),
+])
+def test_tiling_rule_matches_mosaic(block, full, ok):
+    """The rule itself, on blocks either side of Mosaic's boundary."""
+    dims = tuple(1 if b is None else b for b in block)
+    assert pa.tiling_ok(dims, full) is ok
+
+
+def test_whole_array_smem_block_is_exempt_from_tile_001():
+    from jax.experimental.pallas import tpu as pltpu
+    prog = _fake_prog(
+        "smem_scalars", grid=(4,),
+        in_shapes=[(3,), (512,)],
+        in_specs=[pl.BlockSpec((3,), lambda i: (0,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((128,), lambda i: (i,))],
+        out_shapes=[(512,)],
+        out_specs=[pl.BlockSpec((128,), lambda i: (i,))])
+    assert pa.audit_program(prog, case="smem") == []
 
 
 def test_seeded_oversized_block_vmem_001():
