@@ -7,9 +7,8 @@ import traceback
 from benchmarks import (engine_bench, fig1_nusvm_convergence,
                         fig2_size_scaling, fig3_dist_hard_margin,
                         fig4_dist_nusvm, kernels_bench, lm_serve_bench,
-                        roofline, serve_bench, table1_hard_margin,
-                        table3_nu_sweep, table4_density,
-                        theory_iters_comm)
+                        serve_bench, table1_hard_margin, table3_nu_sweep,
+                        table4_density, theory_iters_comm)
 from benchmarks.common import emit, header, write_json
 
 SUITES = [
@@ -25,7 +24,6 @@ SUITES = [
     ("engine", engine_bench),
     ("serve", serve_bench),
     ("lm_serve", lm_serve_bench),
-    ("roofline", roofline),
 ]
 
 

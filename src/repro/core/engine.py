@@ -498,17 +498,21 @@ def _step_packed_core(state: PackedState, key: jax.Array, x_t: jax.Array,
     the jnp backend reads the (d, n_pad) operand."""
     d_eff = d / block_size
     idx = sample_block(key, d, block_size)
-    if backend == "pallas":
-        from repro.kernels import ops as kops
-        cols_t = None                    # gathered inside the kernels
-        delta = kops.momentum_dot_packed(
-            x_t, idx, state.log_lam, state.log_lam_prev, sign, sc.theta)
-    else:
-        cols_t = jnp.take(x_t, idx, axis=0)          # (B, n_pad) CONTIGUOUS
-        lam = jnp.exp(state.log_lam)
-        lam_prev = jnp.exp(state.log_lam_prev)
-        delta = cols_t @ (sign * (lam + sc.theta * (lam - lam_prev)))
-    delta = _all_sum(delta, axis_name)               # round 1
+    # the named scopes only label the ops in a profile (op metadata);
+    # the compiled program is the same with or without them
+    with jax.named_scope("momentum_pass"):
+        if backend == "pallas":
+            from repro.kernels import ops as kops
+            cols_t = None                    # gathered inside the kernels
+            delta = kops.momentum_dot_packed(
+                x_t, idx, state.log_lam, state.log_lam_prev, sign,
+                sc.theta)
+        else:
+            cols_t = jnp.take(x_t, idx, axis=0)      # (B, n_pad) CONTIGUOUS
+            lam = jnp.exp(state.log_lam)
+            lam_prev = jnp.exp(state.log_lam_prev)
+            delta = cols_t @ (sign * (lam + sc.theta * (lam - lam_prev)))
+        delta = _all_sum(delta, axis_name)           # round 1
 
     # Line 4 (round 2): every client performs the identical w update
     # (delta already IS delta+ - delta-, folded by the sign).
@@ -517,13 +521,16 @@ def _step_packed_core(state: PackedState, key: jax.Array, x_t: jax.Array,
     dw = w_new - w_old
 
     # Lines 5-6 (rounds 2-3): ONE packed MWU pass for both classes.
-    log_new, u_new = _dual_update_packed(
-        x_t, idx, cols_t, state.log_lam, state.u, dw, sign, sc, d_eff,
-        axis_name, backend)
+    with jax.named_scope("mwu_pass"):
+        log_new, u_new = _dual_update_packed(
+            x_t, idx, cols_t, state.log_lam, state.u, dw, sign, sc, d_eff,
+            axis_name, backend)
 
     # Round 4: sort-free nu-Saddle capped-simplex projection.
     if project:
-        log_new = _capped_project_packed(log_new, sign, sc.nu, axis_name)
+        with jax.named_scope("nu_projection"):
+            log_new = _capped_project_packed(log_new, sign, sc.nu,
+                                             axis_name)
 
     return PackedState(
         w=state.w.at[idx].set(w_new),
@@ -814,22 +821,25 @@ def chunk_body_slots(state: SlotState, x_t: jax.Array, sign: jax.Array,
         lambda ll, xt, sg: objective_from_duals(ll, xt, sg, axis_name)
     )(state.log_lam, x_t, sign)
 
-    healthy = (jnp.isfinite(state.w).all(axis=-1)
-               & jnp.isfinite(state.u).all(axis=-1)
-               & ~jnp.isnan(state.log_lam).any(axis=-1)
-               & ~jnp.isposinf(state.log_lam).any(axis=-1)
-               & jnp.isfinite(obj))
-    if axis_name is not None:
-        # u / log_lam health is shard-local: agree across point shards
-        # so the replicated ``active`` mask stays replica-consistent.
-        healthy = _all_sum(
-            jnp.where(healthy, 0.0, 1.0), axis_name) == 0.0
+    with jax.named_scope("health_check"):
+        healthy = (jnp.isfinite(state.w).all(axis=-1)
+                   & jnp.isfinite(state.u).all(axis=-1)
+                   & ~jnp.isnan(state.log_lam).any(axis=-1)
+                   & ~jnp.isposinf(state.log_lam).any(axis=-1)
+                   & jnp.isfinite(obj))
+        if axis_name is not None:
+            # u / log_lam health is shard-local: agree across point
+            # shards so the replicated ``active`` mask stays
+            # replica-consistent.
+            healthy = _all_sum(
+                jnp.where(healthy, 0.0, 1.0), axis_name) == 0.0
 
     done = (state.t >= state.max_t) | ~healthy
     if check_gap:
-        gap = jax.vmap(saddle_gap_packed)(state.w, x_t, sign, sp.nu)
-        converged = (sp.gap_tol > 0) & (
-            obj - gap <= sp.gap_tol * jnp.maximum(obj, 1e-12))
+        with jax.named_scope("gap_check"):
+            gap = jax.vmap(saddle_gap_packed)(state.w, x_t, sign, sp.nu)
+            converged = (sp.gap_tol > 0) & (
+                obj - gap <= sp.gap_tol * jnp.maximum(obj, 1e-12))
         done = done | converged
     return state._replace(active=state.active & ~done), obj, healthy
 

@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.core import engine
 from repro.core import preprocess as pp
+from repro.utils.spans import span
 
 
 class SaddleParams(NamedTuple):
@@ -307,57 +308,71 @@ def solve(xp: jax.Array, xm: jax.Array, *, eps: float = 1e-3,
     chunk = min(record_every or num_iters, num_iters)
     backend = "pallas" if use_kernels else "jnp"
 
-    pts = pp.pack_points_to(xp, xm, n_pad or pp.packed_length(n1 + n2), d)
-    if warm_start is None:
-        pstate = engine.init_packed_state(pts.sign, n1, n2, d)
-    else:
-        n1_w = warm_start.log_eta.shape[0]
-        n2_w = warm_start.log_xi.shape[0]
-        lam_old = np.concatenate([np.asarray(warm_start.log_eta),
-                                  np.asarray(warm_start.log_xi)])
-        prev_old = np.concatenate([np.asarray(warm_start.log_eta_prev),
-                                   np.asarray(warm_start.log_xi_prev)])
-        lam = pp.repack_warm_duals(lam_old, n1_w, n2_w, n1, n2, pts.n_pad)
-        prev = pp.repack_warm_duals(prev_old, n1_w, n2_w, n1, n2, pts.n_pad)
-        w = np.zeros((d,), np.float32)
-        w[: warm_start.w.shape[0]] = np.asarray(warm_start.w)
-        pstate = engine.warm_packed_state(
-            pts.x_t, jnp.asarray(w), jnp.asarray(lam), jnp.asarray(prev))
-    sstate = engine.init_slot_state(1, pts.n_pad, d)
-    sstate = engine.admit_into_slot(
-        sstate, 0, pstate, jax.random.key(seed), num_iters)
-    sp = jax.tree.map(lambda v: jnp.asarray(v)[None],
-                      engine.slot_params_row(params, gap_tol))
-    x_t_b, sign_b = pts.x_t[None], pts.sign[None]
+    with span("saddle.solve"):
+        with span("saddle.pack"):
+            pts = pp.pack_points_to(
+                xp, xm, n_pad or pp.packed_length(n1 + n2), d)
+            if warm_start is None:
+                pstate = engine.init_packed_state(pts.sign, n1, n2, d)
+            else:
+                n1_w = warm_start.log_eta.shape[0]
+                n2_w = warm_start.log_xi.shape[0]
+                lam_old = np.concatenate(
+                    [np.asarray(warm_start.log_eta),
+                     np.asarray(warm_start.log_xi)])
+                prev_old = np.concatenate(
+                    [np.asarray(warm_start.log_eta_prev),
+                     np.asarray(warm_start.log_xi_prev)])
+                lam = pp.repack_warm_duals(lam_old, n1_w, n2_w, n1, n2,
+                                           pts.n_pad)
+                prev = pp.repack_warm_duals(prev_old, n1_w, n2_w, n1, n2,
+                                            pts.n_pad)
+                w = np.zeros((d,), np.float32)
+                w[: warm_start.w.shape[0]] = np.asarray(warm_start.w)
+                pstate = engine.warm_packed_state(
+                    pts.x_t, jnp.asarray(w), jnp.asarray(lam),
+                    jnp.asarray(prev))
+            sstate = engine.init_slot_state(1, pts.n_pad, d)
+            sstate = engine.admit_into_slot(
+                sstate, 0, pstate, jax.random.key(seed), num_iters)
+            sp = jax.tree.map(lambda v: jnp.asarray(v)[None],
+                              engine.slot_params_row(params, gap_tol))
+            x_t_b, sign_b = pts.x_t[None], pts.sign[None]
 
-    if driver == "device":
-        sstate, objs_d, marks_d, nc_d = engine.run_solve_slots(
-            sstate, x_t_b, sign_b, sp, num_iters, chunk_steps=chunk,
-            num_chunks=-(-num_iters // chunk), d=d,
-            block_size=block_size, project=nu > 0.0,
-            check_gap=check_gap, backend=backend)
-        # the solve's ONE host transfer: history + chunk count together
-        objs_h, marks_h, nc = jax.device_get((objs_d, marks_d, nc_d))
-        objs = [float(o) for o in objs_h[:nc, 0]]
-        marks = [int(m) for m in marks_h[:nc, 0]]
-    else:
-        objs, marks = [], []
-        done = 0
-        while done < num_iters:
-            ns = min(chunk, num_iters - done)
-            sstate, obj, _healthy = engine.run_chunk_slots(
-                sstate, x_t_b, sign_b, sp, ns, chunk_steps=chunk, d=d,
-                block_size=block_size, project=nu > 0.0,
-                check_gap=check_gap, backend=backend)
-            done += ns
-            objs.append(obj)
-            marks.append(done)
-            if check_gap and not bool(jax.device_get(sstate.active)[0]):
-                marks[-1] = int(jax.device_get(sstate.t)[0])  # gap stop
-                break
-        objs = [float(np.asarray(o)[0]) for o in jax.device_get(objs)]
-    pstate = engine.PackedState(
-        w=sstate.w[0], log_lam=sstate.log_lam[0],
-        log_lam_prev=sstate.log_lam_prev[0], u=sstate.u[0], t=sstate.t[0])
-    return SolveResult(state=unpack_state(pstate, n1, n2),
-                       history=list(zip(marks, objs)))
+        with span("saddle.run", steps=num_iters):
+            if driver == "device":
+                sstate, objs_d, marks_d, nc_d = engine.run_solve_slots(
+                    sstate, x_t_b, sign_b, sp, num_iters,
+                    chunk_steps=chunk, num_chunks=-(-num_iters // chunk), d=d,
+                    block_size=block_size, project=nu > 0.0,
+                    check_gap=check_gap, backend=backend)
+                # the solve's ONE host transfer: history + chunk count
+                objs_h, marks_h, nc = jax.device_get(
+                    (objs_d, marks_d, nc_d))
+                objs = [float(o) for o in objs_h[:nc, 0]]
+                marks = [int(m) for m in marks_h[:nc, 0]]
+            else:
+                objs, marks = [], []
+                done = 0
+                while done < num_iters:
+                    ns = min(chunk, num_iters - done)
+                    sstate, obj, _healthy = engine.run_chunk_slots(
+                        sstate, x_t_b, sign_b, sp, ns, chunk_steps=chunk,
+                        d=d, block_size=block_size, project=nu > 0.0,
+                        check_gap=check_gap, backend=backend)
+                    done += ns
+                    objs.append(obj)
+                    marks.append(done)
+                    if check_gap and not bool(
+                            jax.device_get(sstate.active)[0]):
+                        # gap stop
+                        marks[-1] = int(jax.device_get(sstate.t)[0])
+                        break
+                objs = [float(np.asarray(o)[0])
+                        for o in jax.device_get(objs)]
+        pstate = engine.PackedState(
+            w=sstate.w[0], log_lam=sstate.log_lam[0],
+            log_lam_prev=sstate.log_lam_prev[0], u=sstate.u[0],
+            t=sstate.t[0])
+        return SolveResult(state=unpack_state(pstate, n1, n2),
+                           history=list(zip(marks, objs)))

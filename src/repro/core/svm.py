@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.core import preprocess as pp
 from repro.core import saddle
+from repro.utils.spans import span
 
 
 def split_classes(x: np.ndarray, y: np.ndarray):
@@ -80,30 +81,34 @@ class SaddleSVC:
         return 0.0
 
     def fit(self, x: np.ndarray, y: np.ndarray) -> "SaddleSVC":
-        xp, xm = split_classes(x, y)
-        n1, n2 = len(xp), len(xm)
-        key = jax.random.key(self.seed)
-        k_pre, _ = jax.random.split(key)
-        pre = pp.preprocess(xp, xm, k_pre)
-        nu = self._nu_for(n1, n2)
-        res = saddle.solve(
-            pre.xp, pre.xm, eps=self.eps, beta=self.beta, nu=nu,
-            num_iters=self.num_iters, block_size=self.block_size,
-            seed=self.seed, record_every=self.record_every,
-            use_kernels=self.use_kernels)
-        st = res.state
-        self.history_ = res.history
-        # direction & offset in TRANSFORMED space, mapped back to input
-        # space (recover_hyperplane folds the transform AND the scale,
-        # so w_ . x == w_t . x_t pointwise and the threshold carries
-        # over as-is)
-        eta = jnp.exp(st.log_eta)
-        xi = jnp.exp(st.log_xi)
-        (self.w_, self.b_, self.objective_, self.margin_,
-         w_t) = recover_hyperplane(pre, eta, xi, pre.xp, pre.xm)
-        self.eta_ = np.asarray(eta)
-        self.xi_ = np.asarray(xi)
-        self.state_ = st
+        with span("svm.fit"):
+            with span("svm.split"):
+                xp, xm = split_classes(x, y)
+            n1, n2 = len(xp), len(xm)
+            key = jax.random.key(self.seed)
+            k_pre, _ = jax.random.split(key)
+            with span("svm.preprocess"):
+                pre = pp.preprocess(xp, xm, k_pre)
+            nu = self._nu_for(n1, n2)
+            res = saddle.solve(
+                pre.xp, pre.xm, eps=self.eps, beta=self.beta, nu=nu,
+                num_iters=self.num_iters, block_size=self.block_size,
+                seed=self.seed, record_every=self.record_every,
+                use_kernels=self.use_kernels)
+            st = res.state
+            self.history_ = res.history
+            # direction & offset in TRANSFORMED space, mapped back to
+            # input space (recover_hyperplane folds the transform AND the
+            # scale, so w_ . x == w_t . x_t pointwise and the threshold
+            # carries over as-is)
+            with span("svm.recover"):
+                eta = jnp.exp(st.log_eta)
+                xi = jnp.exp(st.log_xi)
+                (self.w_, self.b_, self.objective_, self.margin_,
+                 w_t) = recover_hyperplane(pre, eta, xi, pre.xp, pre.xm)
+                self.eta_ = np.asarray(eta)
+                self.xi_ = np.asarray(xi)
+            self.state_ = st
         return self
 
     def decision_function(self, x: np.ndarray) -> np.ndarray:

@@ -171,6 +171,7 @@ from repro.core import svm as svm_mod
 from repro.serve import faults as faults_mod
 from repro.serve.scheduler import (RequestFailure, ResultNotReady,
                                    Scheduler, Status)
+from repro.utils.spans import span
 
 
 @dataclass
@@ -518,21 +519,23 @@ class SolverService:
                 f"bucket ladder (max_points={self.max_points}, "
                 f"max_dim={self.max_dim})")
         rid = self._next_id
-        self._next_id += 1
-        xp, xm = svm_mod.split_classes(req.x, req.y)   # raises on 1 class
-        n1, n2 = len(xp), len(xm)
-        saddle.validate_nu(req.nu, n1, n2)
-        k_pre, _ = jax.random.split(jax.random.key(req.seed))
-        pre = pp.preprocess(xp, xm, k_pre)
-        self._enqueue(rid, req, n1, n2, pre.xp.shape[1],
-                      priority=priority, deadline=deadline)
-        self._pre_cache[rid] = _Admission(
-            pre=pre, xp_t=pre.xp, xm_t=pre.xm, warm=None,
-            tenant=rid if req.stream else None)
-        if req.stream:
-            self._tenants[rid] = _Tenant(pre, pre.xp, pre.xm, req)
-            self._tenants[rid].live_rid = rid
-            self._rid_tenant[rid] = rid
+        with span("svc.submit", rid=rid, n=x.shape[0], d=x.shape[1]):
+            self._next_id += 1
+            xp, xm = svm_mod.split_classes(req.x, req.y)   # raises on 1 class
+            n1, n2 = len(xp), len(xm)
+            saddle.validate_nu(req.nu, n1, n2)
+            with span("svc.preprocess"):
+                k_pre, _ = jax.random.split(jax.random.key(req.seed))
+                pre = pp.preprocess(xp, xm, k_pre)
+            self._enqueue(rid, req, n1, n2, pre.xp.shape[1],
+                          priority=priority, deadline=deadline)
+            self._pre_cache[rid] = _Admission(
+                pre=pre, xp_t=pre.xp, xm_t=pre.xm, warm=None,
+                tenant=rid if req.stream else None)
+            if req.stream:
+                self._tenants[rid] = _Tenant(pre, pre.xp, pre.xm, req)
+                self._tenants[rid].live_rid = rid
+                self._rid_tenant[rid] = rid
         return rid
 
     def _enqueue(self, rid: int, req: FitRequest, n1: int, n2: int,
@@ -763,54 +766,56 @@ class SolverService:
         for lane, ticket in self._sched.admit(group):
             req = ticket.payload
             adm = self._pre_cache.pop(ticket.rid)
-            xp_t, xm_t = adm.xp_t, adm.xm_t
-            # preprocess() already padded d to a power of two, so the
-            # request's dimensionality IS the batch's d rung
-            assert xp_t.shape[1] == d_pad, (xp_t.shape, batch.bucket)
-            n1, n2 = xp_t.shape[0], xm_t.shape[0]
-            pts = pp.pack_points(xp_t, xm_t, pad_to=n_pad)
-            params = saddle.make_params(
-                n1 + n2, d_pad, req.eps, req.beta, nu=req.nu,
-                block_size=req.block_size)
-            # the SAME budget derivation as saddle.solve (shared
-            # helper), so a request's schedule equals its solo solve's
-            num_iters = saddle.resolve_num_iters(
-                req.num_iters, d_pad, req.eps, req.beta, n1 + n2,
-                req.block_size)
+            with span("svc.admit", rid=ticket.rid, lane=lane,
+                      warm=int(adm.warm is not None)):
+                xp_t, xm_t = adm.xp_t, adm.xm_t
+                # preprocess() already padded d to a power of two, so the
+                # request's dimensionality IS the batch's d rung
+                assert xp_t.shape[1] == d_pad, (xp_t.shape, batch.bucket)
+                n1, n2 = xp_t.shape[0], xm_t.shape[0]
+                pts = pp.pack_points(xp_t, xm_t, pad_to=n_pad)
+                params = saddle.make_params(
+                    n1 + n2, d_pad, req.eps, req.beta, nu=req.nu,
+                    block_size=req.block_size)
+                # the SAME budget derivation as saddle.solve (shared
+                # helper), so a request's schedule equals its solo solve's
+                num_iters = saddle.resolve_num_iters(
+                    req.num_iters, d_pad, req.eps, req.beta, n1 + n2,
+                    req.block_size)
 
-            batch.x_t, batch.sign = _write_slot_data(
-                batch.x_t, batch.sign, lane, pts.x_t, pts.sign)
-            if adm.warm is not None:
-                # WARM admission: re-place the carried dual segments at
-                # the new class offsets (appended points seeded at the
-                # uniform level; the next MWU normalizer round
-                # renormalizes each class -- no host-side repair), and
-                # recompute u from the carried w on device.  Both
-                # helpers are jitted OUTSIDE the chunk trace keys, so
-                # the hot executables stay zero-recompile.
-                lam = pp.repack_warm_duals(
-                    adm.warm.log_lam, adm.warm.n1, adm.warm.n2,
-                    n1, n2, n_pad)
-                prev = pp.repack_warm_duals(
-                    adm.warm.log_lam_prev, adm.warm.n1, adm.warm.n2,
-                    n1, n2, n_pad)
-                pstate = engine.warm_packed_state(
-                    pts.x_t, jnp.asarray(adm.warm.w),
-                    jnp.asarray(lam), jnp.asarray(prev))
-            else:
-                pstate = engine.init_packed_state(pts.sign, n1, n2,
-                                                  d_pad)
-            batch.state = engine.admit_into_slot(
-                batch.state, lane, pstate,
-                jax.random.key(req.seed), num_iters)
-            row = engine.slot_params_row(params, req.gap_tol)
-            for f in engine.SlotParams._fields:
-                getattr(batch.sp, f)[lane] = getattr(row, f)
-            batch.sp_dev = None                 # refresh device mirror
-            ticket.note = _Slot(request_id=ticket.rid, req=req,
-                                pre=adm.pre, xp_t=xp_t, xm_t=xm_t,
-                                warm=adm.warm, tenant=adm.tenant,
-                                history=[])
+                batch.x_t, batch.sign = _write_slot_data(
+                    batch.x_t, batch.sign, lane, pts.x_t, pts.sign)
+                if adm.warm is not None:
+                    # WARM admission: re-place the carried dual segments at
+                    # the new class offsets (appended points seeded at the
+                    # uniform level; the next MWU normalizer round
+                    # renormalizes each class -- no host-side repair), and
+                    # recompute u from the carried w on device.  Both
+                    # helpers are jitted OUTSIDE the chunk trace keys, so
+                    # the hot executables stay zero-recompile.
+                    lam = pp.repack_warm_duals(
+                        adm.warm.log_lam, adm.warm.n1, adm.warm.n2,
+                        n1, n2, n_pad)
+                    prev = pp.repack_warm_duals(
+                        adm.warm.log_lam_prev, adm.warm.n1, adm.warm.n2,
+                        n1, n2, n_pad)
+                    pstate = engine.warm_packed_state(
+                        pts.x_t, jnp.asarray(adm.warm.w),
+                        jnp.asarray(lam), jnp.asarray(prev))
+                else:
+                    pstate = engine.init_packed_state(pts.sign, n1, n2,
+                                                      d_pad)
+                batch.state = engine.admit_into_slot(
+                    batch.state, lane, pstate,
+                    jax.random.key(req.seed), num_iters)
+                row = engine.slot_params_row(params, req.gap_tol)
+                for f in engine.SlotParams._fields:
+                    getattr(batch.sp, f)[lane] = getattr(row, f)
+                batch.sp_dev = None                 # refresh device mirror
+                ticket.note = _Slot(request_id=ticket.rid, req=req,
+                                    pre=adm.pre, xp_t=xp_t, xm_t=xm_t,
+                                    warm=adm.warm, tenant=adm.tenant,
+                                    history=[])
 
     # ----------------------------------------------------------- failure
     def _record_failure(self, ticket, status: Status, reason: str) -> None:
@@ -839,8 +844,9 @@ class SolverService:
         batch = group.payload
         # ONE blocking transfer per chunk for all (S,)-sized lifecycle
         # vectors; the big per-slot state only moves for finished slots
-        active, t, obj, healthy = map(np.asarray, jax.device_get(
-            (batch.state.active, batch.state.t, obj, healthy)))
+        with span("svc.wait"):
+            active, t, obj, healthy = map(np.asarray, jax.device_get(
+                (batch.state.active, batch.state.t, obj, healthy)))
         out = []
         for lane, ticket in list(group.slots.items()):
             slot = ticket.note
@@ -870,28 +876,29 @@ class SolverService:
             slot.history.append((int(t[lane]), float(obj[lane])))
             if active[lane]:
                 continue
-            lam = np.asarray(jax.device_get(batch.state.log_lam[lane]))
-            n1 = slot.xp_t.shape[0]
-            n2 = slot.xm_t.shape[0]
-            if slot.tenant is not None:
-                # STREAMING harvest: host-retain the final saddle state
-                # (w + dual momentum; lam is already here) BEFORE the
-                # lane is freed -- idle-group eviction drops the device
-                # buffers, so warm state cannot stay slot-resident.
-                ten = self._tenants.get(slot.tenant)
-                if ten is not None and ten.live_rid == slot.request_id:
-                    w_h, prev_h = map(np.asarray, jax.device_get(
-                        (batch.state.w[lane],
-                         batch.state.log_lam_prev[lane])))
-                    ten.warm = _WarmState(
-                        w=w_h, log_lam=lam, log_lam_prev=prev_h,
-                        n1=n1, n2=n2)
-                    ten.live_rid = None
-                self._rid_tenant.pop(slot.request_id, None)
-            eta = jnp.exp(jnp.asarray(lam[:n1]))
-            xi = jnp.exp(jnp.asarray(lam[n1:n1 + n2]))
-            w, b, objective, margin, _ = svm_mod.recover_hyperplane(
-                slot.pre, eta, xi, slot.xp_t, slot.xm_t)
+            with span("svc.recover", rid=slot.request_id):
+                lam = np.asarray(jax.device_get(batch.state.log_lam[lane]))
+                n1 = slot.xp_t.shape[0]
+                n2 = slot.xm_t.shape[0]
+                if slot.tenant is not None:
+                    # STREAMING harvest: host-retain the final saddle state
+                    # (w + dual momentum; lam is already here) BEFORE the
+                    # lane is freed -- idle-group eviction drops the device
+                    # buffers, so warm state cannot stay slot-resident.
+                    ten = self._tenants.get(slot.tenant)
+                    if ten is not None and ten.live_rid == slot.request_id:
+                        w_h, prev_h = map(np.asarray, jax.device_get(
+                            (batch.state.w[lane],
+                             batch.state.log_lam_prev[lane])))
+                        ten.warm = _WarmState(
+                            w=w_h, log_lam=lam, log_lam_prev=prev_h,
+                            n1=n1, n2=n2)
+                        ten.live_rid = None
+                    self._rid_tenant.pop(slot.request_id, None)
+                eta = jnp.exp(jnp.asarray(lam[:n1]))
+                xi = jnp.exp(jnp.asarray(lam[n1:n1 + n2]))
+                w, b, objective, margin, _ = svm_mod.recover_hyperplane(
+                    slot.pre, eta, xi, slot.xp_t, slot.xm_t)
             res = FitResult(request_id=slot.request_id, w=w, b=b,
                             objective=objective, margin=margin,
                             iterations=int(t[lane]), bucket=batch.bucket,
@@ -908,82 +915,88 @@ class SolverService:
         -> admit -> one chunk -> harvest (quarantining unhealthy
         slots) -> evict-if-drained.  Returns the requests that
         finished this round."""
-        # Deadline shedding FIRST (opt-in via clock): expired queued
-        # tickets must neither drive the policy pick nor occupy a lane.
-        if self._clock is not None:
-            for g, ticket in self._sched.shed_expired(self._clock()):
-                self._record_failure(
-                    ticket, Status.DEADLINE_EXCEEDED,
-                    f"deadline {ticket.deadline} passed before "
-                    f"admission")
-                self._sched.evict_idle(g)
-        group = self._sched.next_group()
-        if group is None:
-            return []
-        self._admit(group)
-        if not group.slots:
-            return []
-        batch = group.payload
-        n_pad, d_pad = batch.bucket
-        project, check_gap = batch.project, batch.check_gap
-        block_size = next(iter(group.slots.values())).payload.block_size
-        if batch.mesh is None:
-            key = engine.slot_trace_key(group.num_slots, n_pad, d_pad,
-                                        block_size, self.chunk_steps,
-                                        project, check_gap, self.backend)
-        else:
-            key = engine.sharded_slot_trace_key(
-                group.num_slots, n_pad, d_pad, block_size,
-                self.chunk_steps, project, check_gap, self.backend,
-                batch.mesh, batch.slot_axes, batch.point_axes)
-        # Always run FULL chunks: a slot near its budget is frozen by
-        # the per-slot mask at exactly max_t, which keeps every slot's
-        # chunk/key schedule identical to a solo solve with
-        # record_every == chunk_steps (the parity contract).  A
-        # shortened trip count here would give a mid-run-admitted slot
-        # a partial FIRST chunk no solo schedule ever takes.
-        if batch.sp_dev is None:
-            batch.sp_dev = jax.tree.map(jnp.asarray, batch.sp)
-            if batch.sp_sharding is not None:
-                batch.sp_dev = jax.device_put(batch.sp_dev,
-                                              batch.sp_sharding)
-        # Deterministic fault injection (tests/bench only): poison a
-        # targeted lane BEFORE its chunk; the jitted helper is keyed
-        # outside the chunk executables, so zero-recompile accounting
-        # is untouched.  A request's chunk index is the length of its
-        # recorded history.
-        if self._injector is not None:
-            for lane, ticket in group.slots.items():
-                if self._injector.poison_due(ticket.rid,
-                                             len(ticket.note.history)):
-                    batch.state = faults_mod.poison_slot_state(
-                        batch.state, lane)
-        batch.ensure_placement()
-        with self._sched.stats.chunk(key, engine.trace_counts):
+        with span("svc.step"):
+            # Deadline shedding FIRST (opt-in via clock): expired queued
+            # tickets must neither drive the policy pick nor occupy a lane.
+            if self._clock is not None:
+                for g, ticket in self._sched.shed_expired(self._clock()):
+                    self._record_failure(
+                        ticket, Status.DEADLINE_EXCEEDED,
+                        f"deadline {ticket.deadline} passed before "
+                        f"admission")
+                    self._sched.evict_idle(g)
+            group = self._sched.next_group()
+            if group is None:
+                return []
+            self._admit(group)
+            if not group.slots:
+                return []
+            batch = group.payload
+            n_pad, d_pad = batch.bucket
+            project, check_gap = batch.project, batch.check_gap
+            block_size = next(iter(group.slots.values())).payload.block_size
             if batch.mesh is None:
-                batch.state, obj, healthy = engine.run_chunk_slots(
-                    batch.state, batch.x_t, batch.sign, batch.sp_dev,
-                    self.chunk_steps,
-                    chunk_steps=self.chunk_steps, d=d_pad,
-                    block_size=block_size, project=project,
-                    check_gap=check_gap, backend=self.backend)
+                key = engine.slot_trace_key(group.num_slots, n_pad, d_pad,
+                                            block_size, self.chunk_steps,
+                                            project, check_gap, self.backend)
             else:
-                batch.state, obj, healthy = \
-                    engine.run_chunk_slots_sharded(
-                        batch.state, batch.x_t, batch.sign,
-                        batch.sp_dev, self.chunk_steps,
-                        mesh=batch.mesh, slot_axes=batch.slot_axes,
-                        point_axes=batch.point_axes,
+                key = engine.sharded_slot_trace_key(
+                    group.num_slots, n_pad, d_pad, block_size,
+                    self.chunk_steps, project, check_gap, self.backend,
+                    batch.mesh, batch.slot_axes, batch.point_axes)
+            # Always run FULL chunks: a slot near its budget is frozen by
+            # the per-slot mask at exactly max_t, which keeps every slot's
+            # chunk/key schedule identical to a solo solve with
+            # record_every == chunk_steps (the parity contract).  A
+            # shortened trip count here would give a mid-run-admitted slot
+            # a partial FIRST chunk no solo schedule ever takes.
+            if batch.sp_dev is None:
+                batch.sp_dev = jax.tree.map(jnp.asarray, batch.sp)
+                if batch.sp_sharding is not None:
+                    batch.sp_dev = jax.device_put(batch.sp_dev,
+                                                  batch.sp_sharding)
+            # Deterministic fault injection (tests/bench only): poison a
+            # targeted lane BEFORE its chunk; the jitted helper is keyed
+            # outside the chunk executables, so zero-recompile accounting
+            # is untouched.  A request's chunk index is the length of its
+            # recorded history.
+            if self._injector is not None:
+                for lane, ticket in group.slots.items():
+                    if self._injector.poison_due(ticket.rid,
+                                                 len(ticket.note.history)):
+                        batch.state = faults_mod.poison_slot_state(
+                            batch.state, lane)
+            batch.ensure_placement()
+            queued = sum(g.queued for g in self._sched.groups)
+            with self._sched.stats.chunk(key, engine.trace_counts), \
+                    span("svc.dispatch", lanes=len(group.slots),
+                         slots=group.num_slots, queued=queued, n_pad=n_pad):
+                if batch.mesh is None:
+                    batch.state, obj, healthy = engine.run_chunk_slots(
+                        batch.state, batch.x_t, batch.sign, batch.sp_dev,
+                        self.chunk_steps,
                         chunk_steps=self.chunk_steps, d=d_pad,
                         block_size=block_size, project=project,
                         check_gap=check_gap, backend=self.backend)
-        out = self._harvest(group, obj, healthy)
-        # Idle-batch eviction: a drained batch's device buffers (slot
-        # state + the (S, d, n) operand) would otherwise leak device
-        # memory across varied request shapes.  The COMPILED executable
-        # survives in the jit cache regardless.
-        self._sched.evict_idle(group)
-        return out
+                else:
+                    batch.state, obj, healthy = \
+                        engine.run_chunk_slots_sharded(
+                            batch.state, batch.x_t, batch.sign,
+                            batch.sp_dev, self.chunk_steps,
+                            mesh=batch.mesh, slot_axes=batch.slot_axes,
+                            point_axes=batch.point_axes,
+                            chunk_steps=self.chunk_steps, d=d_pad,
+                            block_size=block_size, project=project,
+                            check_gap=check_gap, backend=self.backend)
+            with span("svc.harvest"):
+                out = self._harvest(group, obj, healthy)
+            # Idle-batch eviction: a drained batch's device buffers (slot
+            # state + the (S, d, n) operand) would otherwise leak device
+            # memory across varied request shapes.  The COMPILED executable
+            # survives in the jit cache regardless.
+            with span("svc.evict"):
+                self._sched.evict_idle(group)
+            return out
 
     def run(self) -> dict[int, FitResult]:
         """Drain every queue; returns (and RELEASES) every result
