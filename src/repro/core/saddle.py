@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.core import engine
 from repro.core import preprocess as pp
+from repro.kernels import resolve_use_kernels
 from repro.utils.spans import span
 
 
@@ -240,7 +241,7 @@ def solve(xp: jax.Array, xm: jax.Array, *, eps: float = 1e-3,
           beta: float = 0.1, nu: float = 0.0, num_iters: int | None = None,
           block_size: int = 1, seed: int = 0,
           record_every: int | None = None,
-          use_kernels: bool = False, n_pad: int | None = None,
+          use_kernels: bool | None = None, n_pad: int | None = None,
           d_pad: int | None = None, gap_tol: float = 0.0,
           driver: str = "device",
           warm_start: SaddleState | None = None) -> SolveResult:
@@ -283,6 +284,10 @@ def solve(xp: jax.Array, xm: jax.Array, *, eps: float = 1e-3,
         run's own iterations.  The trace keys of the hot chunk
         executables are UNCHANGED -- warm and cold solves at the same
         bucket share one compiled chunk.
+      use_kernels: True runs the step on the Pallas kernels, False on
+        jax.numpy; None (the default) chooses from the platform
+        (:func:`repro.kernels.resolve_use_kernels`: the kernels on a
+        TPU, jnp elsewhere).
 
     The hot loop is the SLOT-BATCHED engine driver at S=1 (one engine
     serves the serial solver and the multi-tenant service; the unpacked
@@ -306,6 +311,7 @@ def solve(xp: jax.Array, xm: jax.Array, *, eps: float = 1e-3,
     if record_every is None and check_gap:
         record_every = GAP_CHECK_EVERY   # else the gap never fires
     chunk = min(record_every or num_iters, num_iters)
+    use_kernels = resolve_use_kernels(use_kernels)
     backend = "pallas" if use_kernels else "jnp"
 
     with span("saddle.solve"):
@@ -339,7 +345,7 @@ def solve(xp: jax.Array, xm: jax.Array, *, eps: float = 1e-3,
                               engine.slot_params_row(params, gap_tol))
             x_t_b, sign_b = pts.x_t[None], pts.sign[None]
 
-        with span("saddle.run", steps=num_iters):
+        with span("saddle.run", steps=num_iters, pallas=int(use_kernels)):
             if driver == "device":
                 sstate, objs_d, marks_d, nc_d = engine.run_solve_slots(
                     sstate, x_t_b, sign_b, sp, num_iters,

@@ -61,14 +61,19 @@ def recover_hyperplane(pre: pp.Preprocessed, eta: jax.Array,
 
 
 class SaddleSVC:
-    """Hard-margin SVM via HM-Saddle (paper Sections 2-3)."""
+    """Hard-margin SVM via HM-Saddle (paper Sections 2-3).
+
+    ``use_kernels`` picks the solver step's backend: True the Pallas
+    kernels, False jax.numpy, None (the default) from the platform (the
+    kernels on a TPU; see :func:`repro.kernels.resolve_use_kernels`).
+    """
 
     nu = 0.0
 
     def __init__(self, eps: float = 1e-3, beta: float = 0.1,
                  num_iters: int | None = None, block_size: int = 1,
                  seed: int = 0, record_every: int | None = None,
-                 use_kernels: bool = False):
+                 use_kernels: bool | None = None):
         self.eps = eps
         self.beta = beta
         self.num_iters = num_iters

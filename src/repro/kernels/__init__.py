@@ -18,3 +18,21 @@ def default_interpret() -> bool:
     import jax
 
     return jax.default_backend() != "tpu"
+
+
+def resolve_use_kernels(use_kernels: bool | None) -> bool:
+    """The solo fit's backend: an explicit ``use_kernels`` wins, and
+    ``None`` picks the Pallas kernels on a TPU and ``jax.numpy``
+    everywhere else.
+
+    On the chip the packed kernels gather the sampled coordinate rows
+    tile by tile inside each pass; the jnp step materializes the
+    (B, n_pad) block with ``jnp.take`` and reads it back, which costs
+    more than both passes.  Off the chip the kernels only run in the
+    interpreter, so jnp is the faster and the tested default there.
+    """
+    if use_kernels is not None:
+        return bool(use_kernels)
+    import jax
+
+    return jax.default_backend() == "tpu"

@@ -18,7 +18,8 @@ Span names, by prefix:
   the host-to-device copy and its dispatch) and ``svm.recover``;
 * ``saddle.*`` -- ``saddle.solve``: ``saddle.pack`` (packing and state
   init) and ``saddle.run`` (the solve's dispatch and its one blocking
-  read; counter ``steps``, the block-step budget);
+  read; counters ``steps``, the block-step budget, and ``pallas``, 1
+  where the step ran on the Pallas kernels and 0 on jax.numpy);
 * ``svc.*`` -- ``SolverService``: ``svc.submit`` (``rid``, ``n``, ``d``)
   around ``svc.preprocess``; ``svc.step`` around ``svc.admit`` (one per
   admitted lane: ``rid``, ``lane``, ``warm``), ``svc.dispatch`` (the

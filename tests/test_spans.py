@@ -61,7 +61,7 @@ def test_fit_spans_nest(tmp_path):
         "saddle.pack": "saddle.solve", "saddle.run": "saddle.solve",
         "svm.recover": "svm.fit"}
     run = spans[names.index("saddle.run")]
-    assert run[3] == {"steps": 64}
+    assert run[3] == {"steps": 64, "pallas": 0}     # jnp off the chip
 
 
 def test_service_step_spans_and_dispatch_counters(tmp_path):

@@ -1,9 +1,13 @@
 """High-level SaddleSVC / SaddleNuSVC behaviour (fit/predict/b offset)."""
 
+import jax
 import numpy as np
 import pytest
 
+from repro.core import engine
 from repro.core.svm import SaddleNuSVC, SaddleSVC, split_classes
+from repro.data import synthetic
+from repro.kernels import resolve_use_kernels
 
 
 def test_hard_margin_separable(blobs_separable):
@@ -70,3 +74,56 @@ def test_use_kernels_plumbed_through_fit(blobs_separable):
     b = SaddleSVC(num_iters=400, seed=3, use_kernels=True).fit(ds.x, ds.y)
     np.testing.assert_allclose(a.w_, b.w_, atol=1e-5)
     np.testing.assert_allclose(a.b_, b.b_, atol=1e-5)
+
+
+@pytest.mark.parametrize("platform,want", [("cpu", False), ("gpu", False),
+                                           ("tpu", True)])
+def test_use_kernels_default_follows_platform(monkeypatch, platform, want):
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert resolve_use_kernels(None) is want
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+@pytest.mark.parametrize("flag", [False, True])
+def test_explicit_use_kernels_wins(monkeypatch, platform, flag):
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert resolve_use_kernels(flag) is flag
+
+
+class _Dispatched(Exception):
+    pass
+
+
+@pytest.mark.parametrize("platform,flag,backend", [
+    ("tpu", None, "pallas"), ("cpu", None, "jnp"),
+    ("tpu", False, "jnp"), ("cpu", True, "pallas")])
+def test_fit_dispatches_resolved_backend(monkeypatch, platform, flag,
+                                         backend):
+    """The fit hands ``run_solve_slots`` the backend that the platform
+    rule (or the explicit flag) chose; the solve stops at that call."""
+    seen = []
+
+    def run_solve_slots(*args, **kw):
+        seen.append(kw["backend"])
+        raise _Dispatched
+
+    monkeypatch.setattr(engine, "run_solve_slots", run_solve_slots)
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    ds = synthetic.blobs(20, 24, 8, gap=0.5, spread=0.4, seed=5)
+    with pytest.raises(_Dispatched):
+        SaddleNuSVC(num_iters=64, use_kernels=flag).fit(ds.x, ds.y)
+    assert seen == [backend]
+
+
+def test_default_fit_matches_jnp_off_the_chip():
+    """Off a TPU the default backend is jnp: a default-constructed fit
+    is the ``use_kernels=False`` fit, bit for bit."""
+    assert jax.default_backend() != "tpu"
+    ds = synthetic.blobs(40, 36, 8, gap=0.4, spread=0.5, seed=11)
+    kw = dict(alpha=0.85, num_iters=512, block_size=4, seed=2)
+    a = SaddleNuSVC(**kw).fit(ds.x, ds.y)
+    b = SaddleNuSVC(use_kernels=False, **kw).fit(ds.x, ds.y)
+    np.testing.assert_array_equal(a.w_, b.w_)
+    assert a.b_ == b.b_
+    assert a.history_ == b.history_
+    np.testing.assert_array_equal(a.eta_, b.eta_)

@@ -120,3 +120,27 @@ def test_slot_chunk_with_kernels_compiles(one_chip, monkeypatch):
         sp, 16, chunk_steps=16, d=d, block_size=b, project=True,
         check_gap=False, backend="pallas").compile().as_text()
     assert text.count("tpu_custom_call") >= 2
+
+
+def test_solo_solve_with_kernels_compiles(one_chip, monkeypatch):
+    """The solo fit's whole-solve executable on the pallas backend, the
+    backend a fit on a TPU defaults to, at the 1M rung (S = 1, B = 128):
+    both passes are kernels, the sampled (B, n_pad) block is never
+    materialized, and the program fits one chip's 16 GB."""
+    monkeypatch.setattr(su, "default_interpret", lambda: False)
+    s, n_pad, d, b = 1, 1 << 20, 256, 128
+    state = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: engine.init_slot_state(s, n_pad, d)))
+    sp = engine.SlotParams(*(_sds(one_chip, (s,))
+                             for _ in engine.SlotParams._fields))
+    compiled = engine.run_solve_slots.lower(
+        state, _sds(one_chip, (s, d, n_pad)), _sds(one_chip, (s, n_pad)),
+        sp, 494, chunk_steps=494, num_chunks=1, d=d, block_size=b,
+        project=True, check_gap=False, backend="pallas").compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert f"{b},{n_pad}]" not in text
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16e9
