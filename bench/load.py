@@ -221,7 +221,37 @@ def _fit_request(cfg, x, y, nu, seed, num_iters=None):
     from repro.serve.solver_service import FitRequest
     return FitRequest(x=x, y=y, nu=nu, eps=cfg["eps"], beta=cfg["beta"],
                       gap_tol=cfg["gap_tol"], seed=seed,
-                      num_iters=num_iters)
+                      num_iters=num_iters,
+                      block_size=cfg.get("block_size", 1))
+
+
+def make_service(cfg: dict, chips: int):
+    """The configuration's ``SolverService``: ``SolverService()`` where
+    the configuration has no ``service`` object; else its lanes, chunk
+    length and point-sharding threshold, on a mesh over the first
+    ``chips`` devices, which has to be the mesh the object states."""
+    from repro.serve.solver_service import SolverService
+
+    spec = cfg.get("service")
+    if spec is None:
+        return SolverService()
+    if spec["mesh_chips"] != chips:
+        raise ValueError(f"the configuration's service spans "
+                         f"{spec['mesh_chips']} chips, the cell {chips}")
+    from repro.launch.mesh import make_test_mesh
+    return SolverService(num_slots=spec["num_slots"],
+                         chunk_steps=spec["chunk_steps"],
+                         mesh=make_test_mesh(chips),
+                         shard_points_above=spec["shard_points_above"],
+                         shard_num_slots=spec["shard_num_slots"])
+
+
+def _window_work(T: float, counted, done: dict, start: dict) -> float:
+    """The window's work in fits: a fit started at a and finished at e
+    did the share (T - a) / (e - a) of its work by T."""
+    return sum(1.0 if done[r][0] <= T
+               else (T - start[r]) / (done[r][0] - start[r])
+               for r in counted if r in done and r in start)
 
 
 class StepLog:
@@ -307,13 +337,11 @@ def open_loop(run: Run) -> dict:
     fits in flight at the end count by what they did there; they are
     followed to their results for up to ``follow_s``.  Arrivals are
     scheduled ``drain_s`` past the window."""
-    from repro.serve.solver_service import SolverService
-
     cfg, tr = run.cfg, run.traffic
     sets = [cfg["sets"][name] for name in tr["sets"]]
     pool = _pool(run, sets)
     nus = [nu_of(cfg["alpha"], s["n1"], s["n2"]) for s in sets]
-    svc = SolverService()
+    svc = make_service(cfg, run.cell["chips"])
 
     with run.span("bench.warm"):
         for i, s in enumerate(sets):
@@ -405,11 +433,7 @@ def open_loop(run: Run) -> dict:
         counted = [r for r in admitted if admitted[r] < T]
     missing = [r for r in counted if r not in done]
     lat_ms = [1e3 * (done[r][0] - due[r]) for r in counted if r in done]
-    # the window's work in fits: a fit admitted at a and finished at e
-    # did the share (T - a) / (e - a) of its work by T
-    work = sum(1.0 if done[r][0] <= T
-               else (T - admitted[r]) / (done[r][0] - admitted[r])
-               for r in counted if r in done and r in admitted)
+    work = _window_work(T, counted, done, admitted)
     e2e = {"fits_per_s": work / T}
     tails = {}
     if lat_ms:
@@ -443,4 +467,131 @@ def open_loop(run: Run) -> dict:
             "answers": answers, "notes": notes}
 
 
-MODES = {"closed_fit": closed_fit, "open_loop": open_loop}
+# ------------------------------------------- service: closed loop
+def closed_service(run: Run) -> dict:
+    """``clients`` callers, each keeping one fit in flight in
+    ``SolverService``: the user of a group of large fits.  When a
+    caller's result comes back from ``step()`` it submits its next fit
+    at once, to the next problem of a ``pool`` made from the seed, with
+    a new solver seed each time.  The callers go on until the run ends.
+
+    The window's work in fits: a fit submitted before the window's end
+    counts by the share of its time from the start of its submit to its
+    result that falls inside the window, and is followed to its result
+    for up to ``follow_s``.  A fit's time starts at its submit because
+    ``submit`` runs the program's intake (the class split, the copy to
+    the device, Algorithm 1's transform), which a caller waits for; from
+    admission on, the fits of a group finish together, so lane time
+    alone would count whole groups.  A traced run traces from the first
+    ``step()`` at or after ``trace_s`` before the window's end until
+    ``trace_s`` have passed and two chunks have run."""
+    import jax
+
+    cfg, tr = run.cfg, run.traffic
+    n1, n2, d = cfg["n1"], cfg["n2"], cfg["d"]
+    nu = nu_of(cfg["alpha"], n1, n2)
+    with run.span("bench.data"):
+        problems = [data.problem(run.seed, n1, n2, d, j,
+                                 beta2=cfg["beta2"]) + (nu,)
+                    for j in range(tr["pool"])]
+    svc = make_service(cfg, run.cell["chips"])
+    clients = tr["clients"]
+
+    with run.span("bench.warm"):
+        # one chunk each fills every lane of the group once
+        for c in range(clients):
+            x, y, _ = problems[c % len(problems)]
+            svc.submit(_fit_request(
+                cfg, x, y, nu, c,
+                num_iters=svc.chunk_steps * cfg["block_size"]))
+        svc.run()
+
+    T = run.seconds
+    done, problem, start = {}, {}, {}
+    failed_ids = set()
+    in_flight = []
+    submit_s = []
+    trace = {"on": None, "chunks": 0, "done": run.tracer is None}
+    t_open = run.open_window()
+    log = StepLog(run, t_open)
+
+    def submit():
+        k = len(problem)
+        prob = problems[k % len(problems)]
+        t = CLOCK()
+        with run.span("bench.submit"):
+            rid = svc.submit(_fit_request(cfg, prob[0], prob[1], nu,
+                                          solver_seed(run.seed, k)))
+        submit_s.append(CLOCK() - t)
+        problem[rid] = prob
+        start[rid] = t - t_open
+        in_flight.append(rid)
+
+    for _ in range(clients):
+        submit()
+    while True:
+        if not trace["done"] and trace["on"] is None \
+                and CLOCK() - t_open >= T - tr["trace_s"]:
+            run.trace_on()
+            trace["on"] = CLOCK()
+        info = {"in_flight": len(in_flight)}
+        with log.step(info), run.span("bench.step"):
+            out = svc.step()
+        t_done = CLOCK() - t_open
+        if trace["on"] is not None and not trace["done"]:
+            trace["chunks"] += 1
+            if trace["chunks"] >= 2 and \
+                    CLOCK() - trace["on"] >= tr["trace_s"]:
+                run.trace_off()
+                trace["done"] = True
+        for r in out:
+            done[r.request_id] = (t_done, r)
+        ended = {r.request_id for r in out}
+        for r in in_flight:
+            if r not in ended and svc.status(r).name in (
+                    "FAILED", "CANCELLED", "DEADLINE_EXCEEDED"):
+                failed_ids.add(r)
+                ended.add(r)
+        info.update(done=len(out), ended=len(ended))
+        in_flight[:] = [r for r in in_flight if r not in ended]
+        # the run ends once every fit started in the window has ended,
+        # before the callers' next submits
+        now = CLOCK() - t_open
+        if now >= T and trace["done"] and all(
+                r in done or r in failed_ids for r in start
+                if start[r] < T):
+            break
+        if now >= T + tr["follow_s"]:
+            break
+        for _ in ended:
+            submit()
+    run.trace_off()
+    run.close_window()
+    notes = log.close()
+    notes["peak_bytes_by_device"] = {
+        str(dv.id): (dv.memory_stats() or {}).get("peak_bytes_in_use")
+        for dv in jax.devices()[:run.cell["chips"]]}
+
+    counted = [r for r in start if start[r] < T]
+    missing = [r for r in counted if r not in done]
+    work = _window_work(T, counted, done, start)
+    fits_by_T = sum(1 for r in done if done[r][0] <= T)
+    notes.update({
+        "submitted": len(problem), "completed": len(done),
+        "completed_by_window_end": fits_by_T, "window_work_fits": work,
+        "submit_s_mean": float(np.mean(submit_s)),
+        # the intake's time in the window: split, copy, transform
+        "submit_s_in_window": sum(min(s, T - start[r]) for r, s in
+                                  zip(start, submit_s) if start[r] < T),
+        "iterations_mean": float(np.mean(
+            [done[r][1].iterations for r in done] or [0]))})
+    answers = [(problem[r], (done[r][1].w, done[r][1].b,
+                             done[r][1].objective))
+               for r in counted if r in done]
+    return {"e2e": {"fits_per_s": work / T}, "attempted": len(counted),
+            "failed": len(missing),
+            "answers": answers, "notes": notes}
+
+
+MODES = {"closed_fit": closed_fit, "open_loop": open_loop,
+         "closed_service": closed_service}

@@ -12,7 +12,9 @@ op stats, so this module reads the same file again:
 * :func:`load` turns it into plain event tuples, once per process;
 * :func:`reduce` gives the program's spans (name, start, end, counters)
   and each device op's interval, the executable whose module run holds
-  it, and the innermost of the scopes above on its path.
+  it, and the innermost of the scopes above on its path; and the same
+  per device with the op's name, for the readers of a cell on several
+  chips, which take the mean over the chips (:func:`per_chip_pct`).
 
 Where the scope path is (read by hand from a TPU v5 lite trace): the
 op events of a TPU plane's ``XLA Ops`` line carry only their timing
@@ -174,6 +176,10 @@ class Program:
     spans: list         # (name, start s, end s, counters), by start
     ops: list           # (executable, scope or None, start s, end s)
     exec_s: dict        # executable -> device seconds of its module runs
+    # per device: [(executable, scope or None, op name, start s, end s)]
+    # and {executable: device seconds of its module runs}
+    dev_ops: dict = dataclasses.field(default_factory=dict)
+    dev_exec_s: dict = dataclasses.field(default_factory=dict)
 
 
 def reduce(events: dict) -> Program:
@@ -185,17 +191,21 @@ def reduce(events: dict) -> Program:
                    key=lambda sp: sp[1])
     modules: dict[str, list] = {}
     exec_s: dict[str, float] = {}
+    dev_exec_s: dict[str, dict] = {}
     for dev, line, name, start, dur, _ in events["device"]:
         if line == trace.MODULES_LINE:
             k = trace.executable_name(name)
             modules.setdefault(dev, []).append(
                 (start * 1e-9, (start + dur) * 1e-9, k))
             exec_s[k] = exec_s.get(k, 0.0) + dur * 1e-9
+            per = dev_exec_s.setdefault(dev, {})
+            per[k] = per.get(k, 0.0) + dur * 1e-9
     starts = {}
     for dev, runs in modules.items():
         runs.sort()
         starts[dev] = np.asarray([r[0] for r in runs])
     ops = []
+    dev_ops: dict[str, list] = {}
     for dev, line, name, start, dur, path in events["device"]:
         if line != trace.OPS_LINE or name.startswith(trace.CONTAINERS):
             continue
@@ -203,8 +213,12 @@ def reduce(events: dict) -> Program:
         s = start * 1e-9
         i = int(np.searchsorted(starts.get(dev, []), s, side="right")) - 1
         k = runs[i][2] if i >= 0 and s <= runs[i][1] else None
-        ops.append((k, innermost_scope(path), s, s + dur * 1e-9))
-    return Program(spans=spans, ops=ops, exec_s=exec_s)
+        scope = innermost_scope(path)
+        ops.append((k, scope, s, s + dur * 1e-9))
+        dev_ops.setdefault(dev, []).append(
+            (k, scope, name, s, s + dur * 1e-9))
+    return Program(spans=spans, ops=ops, exec_s=exec_s, dev_ops=dev_ops,
+                   dev_exec_s=dev_exec_s)
 
 
 @functools.lru_cache(maxsize=None)
@@ -249,3 +263,26 @@ def scope_pct(prog: Program | None, executable: str,
     merged = trace.union(iv)
     return 100.0 * float(np.sum(merged[:, 1] - merged[:, 0])) \
         / prog.exec_s[executable]
+
+
+def per_chip_pct(prog: Program | None, executable: str,
+                 pick) -> float | None:
+    """Per device, the union of the intervals of ``executable``'s ops
+    for which ``pick(op name, scope)`` holds, over that device's time in
+    the executable's module runs; the mean over the devices that ran the
+    executable, in percent.  None where no op of it is picked."""
+    if not prog:
+        return None
+    shares, found = [], False
+    for dev, per in prog.dev_exec_s.items():
+        if not per.get(executable):
+            continue
+        iv = [(s, e) for k, sc, name, s, e in prog.dev_ops.get(dev, ())
+              if k == executable and pick(name, sc)]
+        found = found or bool(iv)
+        merged = trace.union(iv)
+        shares.append(float(np.sum(merged[:, 1] - merged[:, 0]))
+                      / per[executable])
+    if not found:
+        return None
+    return 100.0 * sum(shares) / len(shares)

@@ -1,8 +1,9 @@
 """The control: the plain reference put in the program's place and
 computed in bfloat16 fails the cell's limit, while in float32 it meets
 it, as the program does.  At sizes a CPU test holds, with the cells'
-widths and request settings: the solo configuration at 2^13 + 2^13
-points x 256 (B = 128), the service configuration at a small
+widths and request settings: the solo configuration and the meshed one
+(the same problem and solver settings) at 2^13 + 2^13 points x 256
+(B = 128), the service configuration at a small
 phishing-like problem (B = 1, the gap stop at 0.05).  The chip readings
 at the cells' own sizes are in PERF.md."""
 
@@ -19,6 +20,10 @@ BENCH = os.path.join(os.path.dirname(__file__), "..")
 CASES = {  # config, cell whose limit applies, class sizes and d
     "solo": ("dense_1m_nu", "solo_nu_1m", 1 << 13, 1 << 13, 256),
     "service": ("libsvm_tenants", "libsvm_steady", 300, 240, 12),
+    # the meshed cell solves the solo cell's problem; its control is the
+    # same single-device reference
+    "mesh": ("dense_1m_nu_mesh4", "mesh_points_1m_x8", 1 << 13, 1 << 13,
+             256),
 }
 
 

@@ -10,7 +10,8 @@ import pytest
 
 from bench.tests import tiny
 
-CELLS = ["solo_nu_1m", "libsvm_steady", "libsvm_overload"]
+CELLS = ["solo_nu_1m", "libsvm_steady", "libsvm_overload",
+         "mesh_points_1m_x8"]
 
 
 def _state_unchanged(monkeypatch):

@@ -8,7 +8,8 @@ import pytest
 from bench import run as harness
 from bench.tests import tiny
 
-CELLS = ["solo_nu_1m", "libsvm_steady", "libsvm_overload"]
+CELLS = ["solo_nu_1m", "libsvm_steady", "libsvm_overload",
+         "mesh_points_1m_x8"]
 
 
 @pytest.mark.parametrize("cell", CELLS)
